@@ -93,6 +93,9 @@ def main(argv: list[str] | None = None) -> None:
                     help="comma-separated subset of suites to run")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
     from benchmarks import (
         chaos_soak, decode_bench, error_bounds, fig4_breakdown, fig5_shuffle,
         fig6_time_reduction, fig7_accuracy, fig8_vs_sampling, fig9_k_sweep,

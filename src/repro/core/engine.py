@@ -22,7 +22,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.obs.trace import current_tracer
 
@@ -143,12 +142,12 @@ class MapReduce:
         out_mode = combine.mode
         out_specs = P(axis) if out_mode == "identity" else P()
 
-        fn = shard_map(
+        fn = jax.shard_map(
             shard_body,
             mesh=self.mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         # Meter shuffle bytes: what each shard contributes to the collective.
         shard_args_shapes = []

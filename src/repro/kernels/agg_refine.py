@@ -28,7 +28,7 @@ from repro.kernels.ref import NEG
 
 def _kernel(idx_ref, use_ref, cnt_ref, q_ref, k_ref, v_ref,
             out_m, out_l, out_acc, m_s, l_s, acc_s,
-            *, hkv, group, cap, dk, dv, scale):
+            *, hkv, cap, scale):
     bi = pl.program_id(0)
     ri = pl.program_id(1)
 
@@ -38,37 +38,44 @@ def _kernel(idx_ref, use_ref, cnt_ref, q_ref, k_ref, v_ref,
         l_s[...] = jnp.zeros_like(l_s[...])
         acc_s[...] = jnp.zeros_like(acc_s[...])
 
-    q = q_ref[0].astype(jnp.float32).reshape(hkv, group, dk)
-    k = k_ref[0, 0].astype(jnp.float32).reshape(cap, hkv, dk)
-    v = v_ref[0, 0].astype(jnp.float32).reshape(cap, hkv, dv)
-
     cnt = cnt_ref[bi, idx_ref[bi, ri]]
     rows = jax.lax.broadcasted_iota(jnp.int32, (1, cap), 1)
     live = (
         (rows < jnp.minimum(cnt, cap)) & (cnt > 0) & (use_ref[bi, ri] != 0)
     )                                                       # [1, cap]
 
-    logits = jnp.einsum("kgd,ckd->kgc", q, k) * scale       # [Hkv,G,C]
-    logits = jnp.where(live[:, None, :], logits, NEG)
-    bm = jnp.max(logits, axis=-1)                           # [Hkv,G]
-    bw = jnp.where(logits > NEG / 2,
-                   jnp.exp(logits - bm[..., None]), 0.0)
-    bl = jnp.sum(bw, axis=-1)                               # [Hkv,G]
-    bacc = jnp.einsum("kgc,ckd->kgd", bw, v)                # [Hkv,G,dv]
+    # One KV head at a time: 2-D [G, dk] x [cap, dk] contractions, the
+    # shapes Mosaic lowers onto the MXU without in-kernel reshapes.
+    for h in range(hkv):
+        q = q_ref[h].astype(jnp.float32)                    # [G, dk]
+        k = k_ref[:, h, :].astype(jnp.float32)              # [cap, dk]
+        v = v_ref[:, h, :].astype(jnp.float32)              # [cap, dv]
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                           # [G, cap]
+        logits = jnp.where(live, logits, NEG)
+        bm = jnp.max(logits, axis=-1, keepdims=True)        # [G, 1]
+        bw = jnp.where(logits > NEG / 2, jnp.exp(logits - bm), 0.0)
+        bl = jnp.sum(bw, axis=-1, keepdims=True)            # [G, 1]
+        bacc = jax.lax.dot_general(
+            bw, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                   # [G, dv]
 
-    m_old = m_s[...]
-    m_new = jnp.maximum(m_old, bm)
-    w_old = jnp.exp(m_old - m_new)
-    w_b = jnp.exp(bm - m_new)
-    m_s[...] = m_new
-    l_s[...] = l_s[...] * w_old + bl * w_b
-    acc_s[...] = acc_s[...] * w_old[..., None] + bacc * w_b[..., None]
+        m_old = m_s[h]
+        m_new = jnp.maximum(m_old, bm)
+        w_old = jnp.exp(m_old - m_new)
+        w_b = jnp.exp(bm - m_new)
+        m_s[h] = m_new
+        l_s[h] = l_s[h] * w_old + bl * w_b
+        acc_s[h] = acc_s[h] * w_old + bacc * w_b
 
     @pl.when(ri == pl.num_programs(1) - 1)
     def _():
-        out_m[0] = m_s[...]
-        out_l[0] = l_s[...]
-        out_acc[0] = acc_s[...]
+        out_m[...] = m_s[...]
+        out_l[...] = l_s[...]
+        out_acc[...] = acc_s[...]
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -88,58 +95,45 @@ def agg_refine_attention_pallas(
     if r == 0:
         raise ValueError("empty selection: caller must skip R == 0")
 
-    qf = q.reshape(b, hkv * group, dk)
-    kf = k_slots.reshape(b, kb, cap * hkv * dk)
-    vf = v_slots.reshape(b, kb, cap * hkv * dv)
     idx32 = jnp.clip(top_idx.astype(jnp.int32), 0, kb - 1)
     use32 = use.astype(jnp.int32)
     cnt32 = counts.astype(jnp.int32)
 
+    def seq(bi, ri, idx_ref, use_ref, cnt_ref):
+        return bi, 0, 0, 0
+
+    def bucket(bi, ri, idx_ref, use_ref, cnt_ref):
+        return bi, idx_ref[bi, ri], 0, 0, 0
+
+    # Blocks keep the arrays' own last two dims ([G, dk], [Hkv, dk],
+    # [G, 1], [G, dv]), so no operand is reshaped on the way in.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, r),
         in_specs=[
-            pl.BlockSpec(
-                (1, hkv * group, dk),
-                lambda bi, ri, idx_ref, use_ref, cnt_ref: (bi, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, cap * hkv * dk),
-                lambda bi, ri, idx_ref, use_ref, cnt_ref: (
-                    bi, idx_ref[bi, ri], 0
-                ),
-            ),
-            pl.BlockSpec(
-                (1, 1, cap * hkv * dv),
-                lambda bi, ri, idx_ref, use_ref, cnt_ref: (
-                    bi, idx_ref[bi, ri], 0
-                ),
-            ),
+            pl.BlockSpec((None, hkv, group, dk), seq),
+            pl.BlockSpec((None, None, cap, hkv, dk), bucket),
+            pl.BlockSpec((None, None, cap, hkv, dv), bucket),
         ],
         out_specs=(
-            pl.BlockSpec((1, hkv, group), lambda bi, ri, *_: (bi, 0, 0)),
-            pl.BlockSpec((1, hkv, group), lambda bi, ri, *_: (bi, 0, 0)),
-            pl.BlockSpec(
-                (1, hkv, group, dv), lambda bi, ri, *_: (bi, 0, 0, 0)
-            ),
+            pl.BlockSpec((None, hkv, group, 1), seq),
+            pl.BlockSpec((None, hkv, group, 1), seq),
+            pl.BlockSpec((None, hkv, group, dv), seq),
         ),
         scratch_shapes=[
-            pltpu.VMEM((hkv, group), jnp.float32),
-            pltpu.VMEM((hkv, group), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
             pltpu.VMEM((hkv, group, dv), jnp.float32),
         ],
     )
     out_m, out_l, out_acc = pl.pallas_call(
-        functools.partial(
-            _kernel, hkv=hkv, group=group, cap=cap, dk=dk, dv=dv,
-            scale=scale,
-        ),
+        functools.partial(_kernel, hkv=hkv, cap=cap, scale=scale),
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((b, hkv, group), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, group), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, group, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, group, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, group, dv), jnp.float32),
         ),
         interpret=interpret,
-    )(idx32, use32, cnt32, qf, kf, vf)
-    return out_m, out_l, out_acc
+    )(idx32, use32, cnt32, q, k_slots, v_slots)
+    return out_m[..., 0], out_l[..., 0], out_acc

@@ -37,10 +37,7 @@ def _resolve(force: str | None) -> str | None:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def dispatch_path(force: str | None = None) -> str:

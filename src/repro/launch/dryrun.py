@@ -1,5 +1,9 @@
 import os
+# 512 virtual host devices, and never the accelerator: this driver (and
+# every ``--all`` child, which inherits the environment) compiles on the
+# CPU, so on a machine with a chip it can never take the chip.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

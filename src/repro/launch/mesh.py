@@ -2,10 +2,13 @@
 
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state; the dry-run sets XLA_FLAGS before any jax import.
+Axes are Auto-typed: the model and engine code shard through
+``NamedSharding``/``shard_map``, not explicit-sharding types.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,7 +28,10 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices[:n],
+    )
 
 
 def make_host_mesh(shape=(2, 2), axes=("data", "model")):
@@ -33,4 +39,7 @@ def make_host_mesh(shape=(2, 2), axes=("data", "model")):
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+        devices=jax.devices()[:n],
+    )

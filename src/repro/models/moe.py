@@ -30,18 +30,6 @@ from repro.models import layers
 Params = dict[str, Any]
 
 
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, on any supported JAX version.
-
-    ``jax.lax.axis_size`` only exists in newer releases; ``psum`` of a
-    Python scalar constant is folded statically to the axis size, so both
-    branches return a plain ``int`` usable in shape arithmetic.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def moe_init(key, cfg, *, dtype) -> Params:
     d, e, ff = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
     ks = jax.random.split(key, 5)
@@ -165,7 +153,7 @@ def moe_ep_a2a(
     DeepSeek-style EP dispatch.  Buffers travel in bf16.
     """
     t_loc, d = x.shape
-    n_ranks = axis_size(axis_name)
+    n_ranks = jax.lax.axis_size(axis_name)
     e_total = cfg.n_experts
     e_loc = e_total // n_ranks
     probs, gates, eidx = _route(p["router"], x, cfg.moe_top_k)
@@ -232,7 +220,7 @@ def moe_ep(
     contributions back (baseline collective schedule — see module docstring).
     """
     t_loc, d = x.shape
-    n_ranks = axis_size(axis_name)
+    n_ranks = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     e_loc = cfg.n_experts // n_ranks
 

@@ -110,7 +110,6 @@ def _ffn_apply(p, x, cfg, parallel: ParallelContext, *, is_head=False):
 
 def _moe_ep_sharded(pm, x, cfg, parallel: ParallelContext):
     """shard_map wrapper around moe.moe_ep (DESIGN.md §4, EP over model)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = parallel.mesh
@@ -141,9 +140,9 @@ def _moe_ep_sharded(pm, x, cfg, parallel: ParallelContext):
             out = ep_fn(pl, flat, cfg, axis_name=max_)
             return out.reshape(bl, sl, d)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(param_specs, x_spec),
-            out_specs=x_spec, check_rep=False,
+            out_specs=x_spec, check_vma=False,
         )(pm, x)
 
     # decode / short-seq path: tokens replicated over the model axis, each
@@ -158,7 +157,7 @@ def _moe_ep_sharded(pm, x, cfg, parallel: ParallelContext):
     def body_rep(pl, xl):
         bl, sl, _ = xl.shape
         flat = xl.reshape(bl * sl, d).astype(jnp.float32)
-        n_ranks = moe.axis_size(max_)
+        n_ranks = jax.lax.axis_size(max_)
         rank = jax.lax.axis_index(max_)
         e_loc = cfg.n_experts // n_ranks
         out = moe.moe_apply_local(
@@ -170,9 +169,9 @@ def _moe_ep_sharded(pm, x, cfg, parallel: ParallelContext):
             out = out + shared_out.astype(jnp.float32)
         return out.reshape(bl, sl, d).astype(xl.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body_rep, mesh=mesh, in_specs=(param_specs, x_spec),
-        out_specs=x_spec, check_rep=False,
+        out_specs=x_spec, check_vma=False,
     )(pm, x)
 
 

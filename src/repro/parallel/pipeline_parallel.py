@@ -15,7 +15,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -78,9 +77,9 @@ def pipeline_apply(
         jax.tree_util.tree_map(lambda _: P(axis), stage_params),
         P(),
     )
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, microbatches)
 
 

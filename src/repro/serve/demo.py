@@ -28,21 +28,26 @@ KNN_D, CF_ITEMS, N_CLASSES = 48, 384, 10
 
 def build_demo_server(
     *, knn_points: int = 16_384, cf_users: int = 3_072, batch: int = 4,
+    knn_d: int = KNN_D, cf_items: int = CF_ITEMS, cf_density: float = 0.12,
     **server_kwargs,
 ):
     """Server over synthetic kNN + CF shards; returns (server, queries,
     active, active_mask).
 
-    Extra keyword arguments (``tracer``, ``window_s``, ``slo_objectives``,
-    ``flight``, ...) pass straight through to ``Server`` so the example and
-    the benchmark can opt into observability without forking the fixture.
+    ``knn_points`` training points of ``knn_d`` features plus 64 held-out
+    queries; ``cf_users`` rating rows (the first 8 are the active users,
+    the rest the neighbourhood shard) of ``cf_items`` items at
+    ``cf_density``.  Extra keyword arguments (``tracer``, ``window_s``,
+    ``slo_objectives``, ``flight``, ...) pass straight through to
+    ``Server`` so the example and the benchmark can opt into
+    observability without forking the fixture.
     """
     key = jax.random.PRNGKey(0)
     # One aggregate store shared by both shards: pyramids, cross-ratio
     # merges, and snapshot/warm-start all live in one place.
     store = AggregateStore()
     x, y = make_mfeat_like(
-        key, n_points=knn_points + 64, n_features=KNN_D,
+        key, n_points=knn_points + 64, n_features=knn_d,
         n_classes=N_CLASSES, modes_per_class=24, mode_scale=0.5,
     )
     knn = KNNServable(
@@ -50,8 +55,8 @@ def build_demo_server(
         lsh_key=jax.random.PRNGKey(7), store=store,
     )
     ratings, mask = make_netflix_like(
-        jax.random.fold_in(key, 1), n_users=cf_users, n_items=CF_ITEMS,
-        density=0.12,
+        jax.random.fold_in(key, 1), n_users=cf_users, n_items=cf_items,
+        density=cf_density,
     )
     cf = CFServable(
         ratings[8:] * mask[8:], mask[8:], lsh_key=jax.random.PRNGKey(8),

@@ -1,6 +1,9 @@
 """Multi-device correctness checks (run in a subprocess with 8 host devices).
 
-Prints one line per check: ``OK <name>`` or raises.
+``python multidevice_checks.py <name>`` runs one check (``engine_equivalence``,
+``pipeline_parallel``, ...) and prints ``OK <name>``, or raises.  Meshes
+come from ``launch.mesh.make_host_mesh`` (Auto axes, the sharding mode
+these paths use).
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -11,7 +14,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from repro.launch.mesh import make_host_mesh
 
 assert len(jax.devices()) == 8, jax.devices()
 
@@ -26,7 +31,7 @@ def check_engine_equivalence():
     train_y = jax.random.randint(jax.random.fold_in(key, 1), (512,), 0, 5)
     test_x = jax.random.normal(jax.random.fold_in(key, 2), (32, 16))
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_host_mesh((8,), ("data",))
     eng = MapReduce(mesh, axis="data")
 
     def map_fn(tx, ty):
@@ -53,7 +58,7 @@ def check_pipeline_parallel():
     from repro.parallel.pipeline_parallel import (
         pipeline_apply, sequential_reference,
     )
-    mesh = jax.make_mesh((4,), ("pipe",), devices=jax.devices()[:4])
+    mesh = make_host_mesh((4,), ("pipe",))
     key = jax.random.PRNGKey(0)
     stage_w = jax.random.normal(key, (4, 16, 16)) * 0.3
 
@@ -90,7 +95,7 @@ def check_moe_ep_equivalence():
     p = moe.moe_init(key, cfg, dtype=jnp.float32)
     x = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, cfg.d_model))
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh((2, 4), ("data", "model"))
     ctx = ParallelContext(mesh=mesh, data_axes=("data",), use_ep=True)
     got = jax.jit(lambda pp, xx: _moe_ep_sharded(pp, xx, cfg, ctx))(p, x)
     want = moe.moe_dense(p, x, cfg)
@@ -112,7 +117,7 @@ def check_moe_ep_a2a_equivalence():
     key = jax.random.PRNGKey(5)
     p = moe.moe_init(key, cfg, dtype=jnp.float32)
     x = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, cfg.d_model))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh((2, 4), ("data", "model"))
     ctx = ParallelContext(mesh=mesh, data_axes=("data",), use_ep=True)
     got = jax.jit(lambda pp, xx: _moe_ep_sharded(pp, xx, cfg, ctx))(p, x)
     want = moe.moe_dense(p, x, cfg)
@@ -131,7 +136,7 @@ def check_train_step_sharded():
     from repro.parallel import sharding as shard_lib
 
     cfg = get_config("qwen3-8b", smoke=True)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh((2, 4), ("data", "model"))
     ctx = ParallelContext(mesh=mesh, data_axes=("data",))
     key = jax.random.PRNGKey(0)
     params = init_params(key, cfg)
@@ -157,14 +162,13 @@ def check_elastic_restore():
     key = jax.random.PRNGKey(0)
     tree = {"w": jax.random.normal(key, (64, 8)),
             "b": jnp.arange(8.0)}
-    mesh8 = jax.make_mesh((8,), ("data",))
+    mesh8 = make_host_mesh((8,), ("data",))
     sh8 = NamedSharding(mesh8, P("data"))
     tree8 = {"w": jax.device_put(tree["w"], sh8), "b": tree["b"]}
     with tempfile.TemporaryDirectory() as d:
         ck = Checkpointer(d)
         ck.save(7, tree8, extra={"step": 7})
-        mesh4 = jax.make_mesh((4,), ("elastic",),
-                              devices=jax.devices()[:4])
+        mesh4 = make_host_mesh((4,), ("elastic",))
         sh4 = {"w": NamedSharding(mesh4, P("elastic")),
                "b": NamedSharding(mesh4, P())}
         restored, extra = ck.restore(tree, shardings=sh4)
@@ -176,11 +180,10 @@ def check_elastic_restore():
     print("OK elastic_restore")
 
 
+CHECKS = {
+    name[len("check_"):]: fn
+    for name, fn in list(globals().items()) if name.startswith("check_")
+}
+
 if __name__ == "__main__":
-    check_engine_equivalence()
-    check_pipeline_parallel()
-    check_moe_ep_equivalence()
-    check_moe_ep_a2a_equivalence()
-    check_train_step_sharded()
-    check_elastic_restore()
-    print("ALL_OK")
+    CHECKS[sys.argv[1]]()

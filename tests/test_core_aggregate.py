@@ -2,10 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # container has no hypothesis; deterministic shim
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import aggregate as agg_lib
 from repro.core import correlation as corr_lib

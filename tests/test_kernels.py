@@ -5,10 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:  # container has no hypothesis; deterministic shim
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
 from repro.kernels.aggregated_attention import aggregated_attention_pallas
@@ -316,6 +313,36 @@ def test_cf_refine_kernel(qn, un, ni, b):
     for g, w, name in zip(got, want, ("w_ref", "num_delta", "den_delta")):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+def test_refine_kernels_chunked_walk_equals_one_pass(monkeypatch):
+    """A selection too large for SMEM is walked in chunks; the chunked walk
+    returns exactly what one pass does (CF sums add in the same order)."""
+    from repro.kernels import refine_distances as rd
+
+    key = jax.random.PRNGKey(21)
+    xs = jax.random.normal(key, (50, 10))
+    qs = jax.random.normal(jax.random.fold_in(key, 1), (3, 10))
+    idx = jax.random.randint(jax.random.fold_in(key, 2), (3, 17), 0, 50)
+    valid = jax.random.uniform(jax.random.fold_in(key, 3), (3, 17)) < 0.7
+    r = jax.random.randint(key, (20, 15), 0, 6).astype(jnp.float32)
+    m = (jax.random.uniform(jax.random.fold_in(key, 4), (20, 15)) < 0.4
+         ).astype(jnp.float32)
+    cf_args = ((r * m)[:3], m[:3], (r * m)[3:], m[3:], idx % 17, valid)
+
+    # Unjitted wrappers: each call traces afresh with the current chunk size.
+    dist = refine_distances_pallas.__wrapped__
+    cf = cf_refine_pallas.__wrapped__
+    one_d = dist(qs, xs, idx, valid, interpret=True)
+    one_cf = cf(*cf_args, shrink=8.0, interpret=True)
+    monkeypatch.setattr(rd, "SMEM_PAIRS", 6)
+    assert rd.chunk_selection(3, 17) == (2, 9)
+    np.testing.assert_array_equal(
+        np.asarray(dist(qs, xs, idx, valid, interpret=True)),
+        np.asarray(one_d),
+    )
+    for got, want in zip(cf(*cf_args, shrink=8.0, interpret=True), one_cf):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_cf_refine_all_padding_is_zero():

@@ -214,12 +214,40 @@ def test_budget_policy_reexecution_floor():
 
 # ------------------------------------------------------- multi-device -----
 
-def test_multidevice_checks_subprocess():
-    """Engine/PP/EP/sharded-train/elastic-restore on an 8-device mesh."""
+@pytest.mark.parametrize("check", [
+    "engine_equivalence", "pipeline_parallel", "moe_ep_equivalence",
+    "moe_ep_a2a_equivalence", "train_step_sharded", "elastic_restore",
+])
+def test_multidevice_checks_subprocess(check):
+    """Engine/PP/EP/sharded-train/elastic-restore on an 8-device mesh, one
+    check per subprocess so a failure names its check."""
     script = Path(__file__).parent / "_subproc" / "multidevice_checks.py"
     r = subprocess.run(
-        [sys.executable, str(script)], capture_output=True, text=True,
-        timeout=900,
+        [sys.executable, str(script), check], capture_output=True, text=True,
+        timeout=600,
     )
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
-    assert "ALL_OK" in r.stdout, r.stdout
+    assert f"OK {check}" in r.stdout, r.stdout
+
+
+def test_persistent_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache goes
+    to one fixed directory inside the checkout."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.use_persistent_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first = compile_cache.use_persistent_cache()
+        assert first == compile_cache.use_persistent_cache()
+        assert jax.config.jax_compilation_cache_dir == first
+        repo = Path(__file__).resolve().parents[1]
+        assert Path(first) == repo / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()
