@@ -43,8 +43,8 @@ TINY = bool(os.environ.get("REPRO_BENCH_TINY"))
 # Every one of these must appear in the served batch's span tree.
 REQUIRED_SPANS = (
     "serve.batch", "batcher.wait", "deadline.grant", "cache.lookup",
-    "store.get", "mapreduce", "map.shard", "reduce", "stage1",
-    "stage2.refine",
+    "store.get", "mapreduce", "map.shard", "map.meter", "reduce", "stage1",
+    "stage2.refine", "serve.respond",
 )
 
 

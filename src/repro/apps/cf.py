@@ -226,57 +226,70 @@ def accurateml_map(
     stderr sqrt(varsum)/den is exact, not a per-shard approximation.
     """
     agg = cf_agg.agg
+    # Named scopes put each device phase's ops under one op-name path
+    # component (stage1, stage2.centroids/select/rows/merge), so the
+    # compiled HLO names each op's phase; they are metadata and leave the
+    # HLO ops as they are.
     # ---- stage 1: centroid weights + surrogate contribution ----
-    w_g = kernel_ops.cf_weights(
-        active, active_mask, cf_agg.profile, cf_agg.profile_mask
-    )                                                    # [Q,K]
-    co_g = active_mask @ cf_agg.profile_mask.T
-    w_g = shrink_weights(w_g, co_g)
-    w_g = jnp.where(agg.counts[None, :] > 0, w_g, 0.0)
-    num = w_g @ cf_agg.s                                 # [Q,I]
-    den = jnp.abs(w_g) @ cf_agg.c
+    # (the refined map repeats it, under its own scope)
+    with jax.named_scope(
+        "stage1" if refine_budget <= 0 else "stage2.centroids"
+    ):
+        w_g = kernel_ops.cf_weights(
+            active, active_mask, cf_agg.profile, cf_agg.profile_mask
+        )                                                # [Q,K]
+        co_g = active_mask @ cf_agg.profile_mask.T
+        w_g = shrink_weights(w_g, co_g)
+        w_g = jnp.where(agg.counts[None, :] > 0, w_g, 0.0)
+        num = w_g @ cf_agg.s                             # [Q,I]
+        den = jnp.abs(w_g) @ cf_agg.c
 
     if refine_budget <= 0:
-        if not with_bound:
-            return num, den
-        varsum = jnp.square(w_g) @ cf_agg.cvar           # [Q,I]
-        return num, den, varsum
+        with jax.named_scope("stage1"):
+            if not with_bound:
+                return num, den
+            varsum = jnp.square(w_g) @ cf_agg.cvar       # [Q,I]
+            return num, den, varsum
 
     # ---- stage 2: per-query replacement of top buckets by exact users ----
-    corr = jnp.abs(w_g)                                  # [Q,K]
-    rankings = corr_lib.rank_buckets_multi(corr, agg.counts)
-    idx, valid = jax.vmap(
-        lambda r: agg_lib.refinement_indices(agg, r, refine_budget)
-    )(rankings)                                          # [Q,B] x2
-    covered = jax.vmap(
-        lambda r: agg_lib.buckets_fully_covered(agg, r, refine_budget)
-    )(rankings)                                          # [Q,K]
-    covered = covered & (agg.counts[None, :] > 0)
+    with jax.named_scope("stage2.select"):
+        corr = jnp.abs(w_g)                              # [Q,K]
+        rankings = corr_lib.rank_buckets_multi(corr, agg.counts)
+        idx, valid = jax.vmap(
+            lambda r: agg_lib.refinement_indices(agg, r, refine_budget)
+        )(rankings)                                      # [Q,B] x2
+        covered = jax.vmap(
+            lambda r: agg_lib.buckets_fully_covered(agg, r, refine_budget)
+        )(rankings)                                      # [Q,K]
+        covered = covered & (agg.counts[None, :] > 0)
 
-    # Exact sums must not double-count: only users of fully covered buckets
-    # (per query) replace their bucket's surrogate.
-    use = valid & jnp.take_along_axis(
-        covered, agg.bucket_of[idx], axis=1
-    )                                                    # [Q,B]
+        # Exact sums must not double-count: only users of fully covered
+        # buckets (per query) replace their bucket's surrogate.
+        use = valid & jnp.take_along_axis(
+            covered, agg.bucket_of[idx], axis=1
+        )                                                # [Q,B]
     # Gather-free neighbour selection: the scalar-prefetch kernel reads each
     # selected user's centred/mask rows straight from HBM, forms the shrunk
     # Pearson weight in registers, and accumulates the weighted sums — the
     # [Q,B,I] gathered tensors never materialize.
-    _, num_delta, den_delta = kernel_ops.cf_refine(
-        active, active_mask, ratings, mask, idx, use, shrink=SHRINK
-    )
+    with jax.named_scope("stage2.rows"):
+        _, num_delta, den_delta = kernel_ops.cf_refine(
+            active, active_mask, ratings, mask, idx, use, shrink=SHRINK
+        )
 
     # Subtract the covered buckets' surrogate, add their exact terms.
-    w_g_cov = jnp.where(covered, w_g, 0.0)
-    num = num - w_g_cov @ cf_agg.s + num_delta
-    den = den - jnp.abs(w_g_cov) @ cf_agg.c + den_delta
-    if not with_bound:
-        return num, den
-    # Surrogate variance only over the *unrefined* buckets: covered ones
-    # were replaced by exact per-user terms and carry no surrogate error.
-    w_g_unc = jnp.where(covered, 0.0, w_g)
-    varsum = jnp.square(w_g_unc) @ cf_agg.cvar
-    return num, den, varsum
+    with jax.named_scope("stage2.merge"):
+        w_g_cov = jnp.where(covered, w_g, 0.0)
+        num = num - w_g_cov @ cf_agg.s + num_delta
+        den = den - jnp.abs(w_g_cov) @ cf_agg.c + den_delta
+        if not with_bound:
+            return num, den
+        # Surrogate variance only over the *unrefined* buckets: covered
+        # ones were replaced by exact per-user terms and carry no
+        # surrogate error.
+        w_g_unc = jnp.where(covered, 0.0, w_g)
+        varsum = jnp.square(w_g_unc) @ cf_agg.cvar
+        return num, den, varsum
 
 
 # ---------------------------------------------------------------------------

@@ -335,80 +335,101 @@ def accurateml_map(
     agg = knn_agg.agg
     n_k = agg.means.shape[0]                                  # K (static)
     kk = k + 1 if with_bound else k
+    # Named scopes put each device phase's ops under one op-name path
+    # component (stage1, stage2.centroids/select/rows/merge), so the
+    # compiled HLO names each op's phase; they are metadata and leave the
+    # HLO ops as they are.
     if with_bound:
         # Pack (label, bucket) into one int32 label channel; spread and
         # dispersion gain a zero slot at index K for exact candidates.
-        cent_ids = jnp.arange(n_k, dtype=jnp.int32)
-        cent_comb = knn_agg.bucket_labels * jnp.int32(n_k + 1) + cent_ids
-        spread_ext = jnp.concatenate(
-            [knn_agg.spread, jnp.zeros((1,), jnp.float32)]
-        )
-        disp_ext = jnp.concatenate(
-            [knn_agg.dispersion, jnp.zeros((1,), jnp.float32)]
-        )
+        with jax.named_scope(
+            "stage1" if refine_budget <= 0 else "stage2.merge"
+        ):
+            cent_ids = jnp.arange(n_k, dtype=jnp.int32)
+            cent_comb = knn_agg.bucket_labels * jnp.int32(n_k + 1) + cent_ids
+            spread_ext = jnp.concatenate(
+                [knn_agg.spread, jnp.zeros((1,), jnp.float32)]
+            )
+            disp_ext = jnp.concatenate(
+                [knn_agg.dispersion, jnp.zeros((1,), jnp.float32)]
+            )
 
     if refine_budget <= 0:
         # Pure stage 1: fused distance+top-k over the aggregated points —
         # the [Q, K] matrix is never needed (no ranking to derive from it).
-        if not with_bound:
-            return kernel_ops.distance_topk(
-                test_x, agg.means, knn_agg.bucket_labels, agg.counts > 0, k=k
+        with jax.named_scope("stage1"):
+            if not with_bound:
+                return kernel_ops.distance_topk(
+                    test_x, agg.means, knn_agg.bucket_labels,
+                    agg.counts > 0, k=k,
+                )
+            d, comb = kernel_ops.distance_topk(
+                test_x, agg.means, cent_comb, agg.counts > 0, k=kk
             )
-        d, comb = kernel_ops.distance_topk(
-            test_x, agg.means, cent_comb, agg.counts > 0, k=kk
-        )
-        bid = comb % jnp.int32(n_k + 1)
-        labels = comb // jnp.int32(n_k + 1)
-        bound = _vote_bound(d, labels, spread_ext[bid], disp_ext[bid], k)
-        return d[:, :k], labels[:, :k], bound
+            bid = comb % jnp.int32(n_k + 1)
+            labels = comb // jnp.int32(n_k + 1)
+            bound = _vote_bound(d, labels, spread_ext[bid], disp_ext[bid], k)
+            return d[:, :k], labels[:, :k], bound
 
     # ---- stage 1: initial output + correlations from aggregated points ----
     # The full [Q, K] distances are inherent here: every bucket needs a
     # correlation for the per-query refinement ranking (Alg. 1 line 2).
-    d_cent = pairwise_sq_dists(test_x, agg.means)            # [Q, K]
-    d_cent = jnp.where(agg.counts[None, :] > 0, d_cent, BIG)
-    corr = -d_cent                                           # [Q, K]
+    with jax.named_scope("stage2.centroids"):
+        d_cent = pairwise_sq_dists(test_x, agg.means)        # [Q, K]
+        d_cent = jnp.where(agg.counts[None, :] > 0, d_cent, BIG)
 
     # ---- stage 2: per-query refinement of the top-correlated buckets ----
-    rankings = corr_lib.rank_buckets_multi(corr, agg.counts)  # [Q, K]
-    idx, valid = jax.vmap(
-        lambda r: agg_lib.refinement_indices(agg, r, refine_budget)
-    )(rankings)                                               # [Q, B] x2
-    covered = jax.vmap(
-        lambda r: agg_lib.buckets_fully_covered(agg, r, refine_budget)
-    )(rankings)                                               # [Q, K]
-    covered = covered & (agg.counts[None, :] > 0)
+    with jax.named_scope("stage2.select"):
+        corr = -d_cent                                       # [Q, K]
+        rankings = corr_lib.rank_buckets_multi(corr, agg.counts)  # [Q, K]
+        idx, valid = jax.vmap(
+            lambda r: agg_lib.refinement_indices(agg, r, refine_budget)
+        )(rankings)                                          # [Q, B] x2
+        covered = jax.vmap(
+            lambda r: agg_lib.buckets_fully_covered(agg, r, refine_budget)
+        )(rankings)                                          # [Q, K]
+        covered = covered & (agg.counts[None, :] > 0)
 
     # Gather-free exact distances: each selected original is read straight
     # from HBM by the scalar-prefetch kernel ([Q,B,D] never materializes).
-    d_ref = kernel_ops.refine_distances(test_x, train_x, idx, valid)
-    ref_y = train_y[idx]                                      # [Q, B] ints
-    d_cent_masked = jnp.where(covered, BIG, d_cent)
+    with jax.named_scope("stage2.rows"):
+        d_ref = kernel_ops.refine_distances(test_x, train_x, idx, valid)
+        ref_y = train_y[idx]                                 # [Q, B] ints
 
     # Fused finalize: masked centroids seed the running k-best, refined
     # candidates merge into the same scratch (replaces concatenate+top_k).
-    if not with_bound:
-        best_d, best_l = kernel_ops.candidate_topk(
-            d_cent_masked,
-            jnp.broadcast_to(knn_agg.bucket_labels[None, :], d_cent.shape),
-            k=k,
-        )
-        return kernel_ops.candidate_topk(d_ref, ref_y, best_d, best_l, k=k)
+    with jax.named_scope("stage2.merge"):
+        d_cent_masked = jnp.where(covered, BIG, d_cent)
+        if not with_bound:
+            best_d, best_l = kernel_ops.candidate_topk(
+                d_cent_masked,
+                jnp.broadcast_to(
+                    knn_agg.bucket_labels[None, :], d_cent.shape
+                ),
+                k=k,
+            )
+            return kernel_ops.candidate_topk(
+                d_ref, ref_y, best_d, best_l, k=k
+            )
 
-    best_d, best_c = kernel_ops.candidate_topk(
-        d_cent_masked,
-        jnp.broadcast_to(cent_comb[None, :], d_cent.shape),
-        k=kk,
-    )
-    ref_comb = ref_y * jnp.int32(n_k + 1) + jnp.int32(n_k)
-    d, comb = kernel_ops.candidate_topk(d_ref, ref_comb, best_d, best_c, k=kk)
-    bid = comb % jnp.int32(n_k + 1)
-    labels = comb // jnp.int32(n_k + 1)
-    hidden = _hidden_risk(d_cent_masked, knn_agg.spread, bid, d[:, k], n_k)
-    bound = _vote_bound(
-        d, labels, spread_ext[bid], disp_ext[bid], k, hidden=hidden
-    )
-    return d[:, :k], labels[:, :k], bound
+        best_d, best_c = kernel_ops.candidate_topk(
+            d_cent_masked,
+            jnp.broadcast_to(cent_comb[None, :], d_cent.shape),
+            k=kk,
+        )
+        ref_comb = ref_y * jnp.int32(n_k + 1) + jnp.int32(n_k)
+        d, comb = kernel_ops.candidate_topk(
+            d_ref, ref_comb, best_d, best_c, k=kk
+        )
+        bid = comb % jnp.int32(n_k + 1)
+        labels = comb // jnp.int32(n_k + 1)
+        hidden = _hidden_risk(
+            d_cent_masked, knn_agg.spread, bid, d[:, k], n_k
+        )
+        bound = _vote_bound(
+            d, labels, spread_ext[bid], disp_ext[bid], k, hidden=hidden
+        )
+        return d[:, :k], labels[:, :k], bound
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,11 @@ The engine is deliberately thin: apps give it a ``map_fn`` (typically the
 two-stage refine skeleton) and a ``CombineSpec``.  It also *meters* shuffle
 bytes so the fig.5 benchmark can report the paper's percentage-shuffle-cost
 metric from the same code path that runs on the pod mesh.
+
+Spans (``repro.obs.trace``): ``mapreduce`` holds ``map.shard`` (the map's
+dispatch), ``map.meter`` (the shuffle metering) and ``reduce`` (the
+combine).  They time host work only and never block: the device trace,
+on the same clock, says when the enqueued work ran.
 """
 from __future__ import annotations
 
@@ -72,49 +77,41 @@ class MapReduce:
         *sharded_args: Any,
         replicated_args: tuple = (),
     ) -> Any:
-        # Tracing: spans attach to the context tracer installed by the
-        # caller (repro.serve installs its per-batch tracer around execute).
-        # With a live tracer the engine blocks at stage boundaries so span
-        # durations mean "work finished here", not "dispatch returned here";
-        # with the default NULL_TRACER nothing blocks and nothing records.
+        # Spans attach to the context tracer installed by the caller
+        # (repro.serve installs its per-batch tracer around execute); with
+        # the default NULL_TRACER nothing records.
         tracer = current_tracer()
 
         if self.mesh is None:
             with tracer.span("mapreduce", mode=combine.mode, shards=1) as mr:
                 with tracer.span("map.shard", shard=0) as m_sp:
                     out = map_fn(*sharded_args, *replicated_args)
-                    if tracer.enabled:
-                        out = jax.block_until_ready(out)
                 # Identity combine keeps outputs shard-local: no shuffle,
                 # same as the mesh path reports.
-                self.last_shuffle_bytes = (
-                    0
-                    if combine.mode == "identity"
-                    else _tree_bytes(
-                        jax.eval_shape(map_fn, *sharded_args, *replicated_args)
-                    )
-                )
+                if combine.mode == "identity":
+                    self.last_shuffle_bytes = 0
+                else:
+                    with tracer.span("map.meter"):
+                        self.last_shuffle_bytes = _tree_bytes(jax.eval_shape(
+                            map_fn, *sharded_args, *replicated_args
+                        ))
                 m_sp.set(shuffle_bytes=self.last_shuffle_bytes)
                 mr.set(shuffle_bytes=self.last_shuffle_bytes)
                 if combine.mode == "all_gather":
-                    stacked = jax.tree_util.tree_map(lambda x: x[None], out)
                     with tracer.span("reduce"):
-                        result = (
+                        stacked = jax.tree_util.tree_map(
+                            lambda x: x[None], out
+                        )
+                        return (
                             combine.reduce_fn(stacked)
                             if combine.reduce_fn else stacked
                         )
-                        if tracer.enabled:
-                            result = jax.block_until_ready(result)
-                    return result
                 if combine.mode == "psum":
                     with tracer.span("reduce"):
-                        result = (
+                        return (
                             combine.reduce_fn(out) if combine.reduce_fn
                             else out
                         )
-                        if tracer.enabled:
-                            result = jax.block_until_ready(result)
-                    return result
                 return out
 
         axis = self.axis
@@ -149,24 +146,25 @@ class MapReduce:
             out_specs=out_specs,
             check_vma=False,
         )
-        # Meter shuffle bytes: what each shard contributes to the collective.
-        shard_args_shapes = []
-        for a in sharded_args:
-            def _slice(x):
-                shape = (x.shape[0] // n_shards,) + x.shape[1:]
-                return jax.ShapeDtypeStruct(shape, x.dtype)
-            shard_args_shapes.append(jax.tree_util.tree_map(_slice, a))
-        map_out_shape = jax.eval_shape(
-            map_fn, *shard_args_shapes, *replicated_args
-        )
-        per_shard = _tree_bytes(map_out_shape)
-        self.last_shuffle_bytes = (
-            per_shard * n_shards if out_mode != "identity" else 0
-        )
-        with tracer.span(
-            "mapreduce", mode=out_mode, shards=n_shards,
-            shuffle_bytes=self.last_shuffle_bytes,
-        ):
+        with tracer.span("mapreduce", mode=out_mode, shards=n_shards) as mr:
+            # Meter shuffle bytes: what each shard contributes to the
+            # collective.
+            with tracer.span("map.meter"):
+                shard_args_shapes = []
+                for a in sharded_args:
+                    def _slice(x):
+                        shape = (x.shape[0] // n_shards,) + x.shape[1:]
+                        return jax.ShapeDtypeStruct(shape, x.dtype)
+                    shard_args_shapes.append(
+                        jax.tree_util.tree_map(_slice, a)
+                    )
+                per_shard = _tree_bytes(jax.eval_shape(
+                    map_fn, *shard_args_shapes, *replicated_args
+                ))
+            self.last_shuffle_bytes = (
+                per_shard * n_shards if out_mode != "identity" else 0
+            )
+            mr.set(shuffle_bytes=self.last_shuffle_bytes)
             if tracer.enabled:
                 # One jit dispatch covers every shard on the mesh path, so
                 # per-shard *time* can't be split honestly; attribute the
@@ -176,10 +174,7 @@ class MapReduce:
                 for i in range(n_shards):
                     tracer.event("map.shard", shard=i, shuffle_bytes=per)
             with tracer.span("map+reduce.fused"):
-                result = fn(*sharded_args, *replicated_args)
-                if tracer.enabled:
-                    result = jax.block_until_ready(result)
-            return result
+                return fn(*sharded_args, *replicated_args)
 
 
 def shard_leading(mesh: Mesh, axis: str, tree: Any) -> Any:

@@ -8,11 +8,14 @@ Layers (trace -> metrics -> probes -> decision)
 ===============================================
 
     [ trace ]    repro.obs.trace — span trees with explicit host clocks
-        |        (never read inside jit).  One served batch yields one
-        |        tree: batcher enqueue->admit waits, the deadline grant,
+        |        (never read inside jit); each live span is also a
+        |        profiler annotation ("host." + name), on the device
+        |        trace's clock, and no span blocks.  One served batch yields
+        |        one tree: batcher enqueue->admit waits, the deadline grant,
         |        the aggregate-cache lookup (hit/built/merged/restored),
-        |        per-shard MapReduce map/combine/reduce with shuffle bytes,
-        |        and stage-2 refinement.  Propagated by contextvar
+        |        per-shard MapReduce map dispatch / shuffle metering / reduce
+        |        with shuffle bytes, stage-2 refinement and the response
+        |        loop.  Propagated by contextvar
         |        (use_tracer / current_tracer): the engine and store pick
         |        the tracer up without threading a parameter; the default
         |        NULL_TRACER makes every call a no-op.  Export: JSON-lines

@@ -2,16 +2,26 @@
 
 A ``Span`` is one named, timed unit of host-side work; spans nest into a
 tree rooted at the outermost open span (one root per served batch in
-``repro.serve``).  Two rules keep this honest on a jit-compiled stack:
+``repro.serve``).  Three rules keep this honest on a jit-compiled stack:
 
-  * **explicit clocks** — a tracer owns one host clock (``perf_counter`` by
-    default, injectable for tests); spans are only ever opened and closed
-    around ``block_until_ready`` boundaries in *host* code, never inside a
-    traced/jitted function (wall-clock reads inside jit would record trace
-    time, not run time);
+  * **host spans time host work** — a tracer owns one host clock
+    (``perf_counter`` by default, injectable for tests); spans are opened
+    and closed in *host* code, never inside a traced/jitted function
+    (wall-clock reads inside jit would record trace time, not run time).
+    Dispatch is asynchronous, so a span around a jitted call covers its
+    dispatch, not the device work it enqueued;
+  * **device work is read from the device trace** — while a ``Tracer`` is
+    live, every span opened with ``span()`` also opens a
+    ``jax.profiler.TraceAnnotation`` named ``"host." + name`` for its
+    lifetime, so under the profiler each span lands on the host timeline on
+    the same clock as the device ops, and an idle gap of the device can be
+    put down to the innermost span covering it.  No span blocks in order to
+    time anything;
   * **explicit time spans** — work whose start predates the current span
     (a request waiting in the queue) is recorded with ``add_span(name, t0,
-    t1)`` using clock values captured where they were meaningful.
+    t1)`` using clock values captured where they were meaningful; such
+    intervals are already over, so they (and ``event``) stay in the
+    tracer's own record and never reach the profiler.
 
 Propagation uses a ``contextvars.ContextVar``: the server installs its
 tracer with ``use_tracer`` around batch execution and deeper layers (the
@@ -35,11 +45,15 @@ import time
 from collections import deque
 from typing import Any, Callable, Iterator
 
+import jax
+
 # Flat-span schema (one JSON object per line of to_jsonl). Bump SCHEMA_VERSION
 # when a key is added/removed; validate_trace_jsonl pins it in CI.
 SCHEMA_VERSION = 1
 SPAN_KEYS = ("schema", "trace", "span", "parent", "name", "t0", "t1",
              "dur_s", "attrs")
+# Prefix of the profiler annotation each live span opens.
+PROFILER_PREFIX = "host."
 
 
 class Span:
@@ -159,12 +173,16 @@ class Tracer:
     # ------------------------------------------------------------------
     @contextlib.contextmanager
     def span(self, name: str, **attrs: Any) -> Iterator[Span]:
-        """Open a child of the current span (or a new root), close on exit."""
+        """Open a child of the current span (or a new root), close on exit.
+
+        The span's profiler annotation opens after it and closes before
+        it, so both nest alike."""
         sp = self._open(name, self.clock())
         if attrs:
             sp.attrs.update(attrs)
         try:
-            yield sp
+            with jax.profiler.TraceAnnotation(PROFILER_PREFIX + name):
+                yield sp
         finally:
             sp.t_end = self.clock()
             self._close(sp)

@@ -48,7 +48,10 @@ Life of a request::
 
 Observability: pass ``tracer=repro.obs.Tracer()`` to ``Server`` and every
 batch records a span tree (batcher wait -> grant -> cache lookup -> per-shard
-map -> refine); see ``repro.obs`` and ``examples/observe_serving.py``.
+map -> refine -> respond); see ``repro.obs`` and
+``examples/observe_serving.py``.  The spans share the profiler's clock (each
+is a ``host.*`` annotation in a device trace) and never block, so a tracer
+adds no sync to the serving loop.
 
 Workloads implement the small ``Servable`` protocol (repro.serve.request);
 ``repro.apps.knn.KNNServable``, ``repro.apps.cf.CFServable``, and

@@ -372,80 +372,87 @@ class Server:
                     proxies = proxy_fn(s1_out, ref_out, batch.n)
             t_end = self.clock()
 
-            # Failure domains absent from this batch's answer (shard died
-            # or is still recovering): flagged on every response — a
-            # degraded answer under the anytime contract, not an error.
-            partial_shards = tuple(
-                getattr(servable, "last_partial_shards", ())
-            )
+            # ---- respond: controller, metrics, responses, SLOs ----
+            with tracer.span("serve.respond"):
+                # Failure domains absent from this batch's answer (shard
+                # died or is still recovering): flagged on every response —
+                # a degraded answer under the anytime contract, not an error.
+                partial_shards = tuple(
+                    getattr(servable, "last_partial_shards", ())
+                )
 
-            # Cold batches (fresh compile or aggregate build) are deploy
-            # cost, not steady-state serving cost: keep them out of the
-            # correction — as are accuracy-SLO deviations (skip/boost),
-            # whose wall time no longer matches the grant's prediction.
-            if warmed and cache_hit and refine_budget == grant.refine_budget:
-                self.controller.observe(
-                    batch.kind, grant.predicted_s, t_end - t_start
+                # Cold batches (fresh compile or aggregate build) are
+                # deploy cost, not steady-state serving cost: keep them out
+                # of the correction — as are accuracy-SLO deviations
+                # (skip/boost), whose wall time no longer matches the
+                # grant's prediction.
+                if (warmed and cache_hit
+                        and refine_budget == grant.refine_budget):
+                    self.controller.observe(
+                        batch.kind, grant.predicted_s, t_end - t_start
+                    )
+                self._seen_combos |= combos
+                self.metrics.record_batch(
+                    shuffle_bytes, occupancy=batch.n,
+                    cache_source=cache_source,
                 )
-            self._seen_combos |= combos
-            self.metrics.record_batch(
-                shuffle_bytes, occupancy=batch.n, cache_source=cache_source
-            )
-            if refine_skipped or boosted:
-                self.metrics.record_accuracy_decision(
-                    skipped=refine_skipped, boosted=boosted
+                if refine_skipped or boosted:
+                    self.metrics.record_accuracy_decision(
+                        skipped=refine_skipped, boosted=boosted
+                    )
+                root.set(
+                    eps=eps_used, shuffle_bytes=shuffle_bytes,
+                    refined=refined_answers is not None,
+                    refine_skipped=refine_skipped, boosted=boosted,
                 )
-            root.set(
-                eps=eps_used, shuffle_bytes=shuffle_bytes,
-                refined=refined_answers is not None,
-                refine_skipped=refine_skipped, boosted=boosted,
-            )
 
-            responses = []
-            for i, req in enumerate(batch.requests):
-                stage1_latency = t_stage1 - req.arrival_t
-                total_latency = (
-                    t_end - req.arrival_t if refined_answers is not None
-                    else stage1_latency
-                )
-                bound = bounds[i] if bounds is not None else None
-                resp = Response(
-                    rid=req.rid,
-                    kind=req.kind,
-                    stage1=stage1_answers[i],
-                    refined=refined_answers[i] if refined_answers else None,
-                    eps_granted=eps_used,
-                    compression_ratio=grant.compression_ratio,
-                    deadline_s=req.deadline_s,
-                    queue_wait_s=t_start - req.arrival_t,
-                    stage1_latency_s=stage1_latency,
-                    total_latency_s=total_latency,
-                    deadline_met=stage1_latency <= req.deadline_s,
-                    escalated=grant.escalate,
-                    reexecuted=req.reexecution,
-                    cache_hit=cache_hit,
-                    batch_size=batch.n,
-                    accuracy_proxy=(
-                        float(proxies[i]) if proxies is not None else None
-                    ),
-                    partial_shards=partial_shards,
-                    error_bound=bound,
-                    accuracy_met=(
-                        bound.met(req.max_error)
-                        if bound is not None and req.max_error is not None
-                        else None
-                    ),
-                    refine_skipped=refine_skipped,
-                )
-                responses.append(resp)
-                self.metrics.record(resp)
-                if grant.escalate and not req.reexecution:
-                    self._requeue_for_reexecution(req)
-            if self.slo is not None:
-                # Evaluate inside the batch span so alert transitions land
-                # as slo.alert events on this batch's tree.
-                self.slo.evaluate()
-            return responses
+                responses = []
+                for i, req in enumerate(batch.requests):
+                    stage1_latency = t_stage1 - req.arrival_t
+                    total_latency = (
+                        t_end - req.arrival_t if refined_answers is not None
+                        else stage1_latency
+                    )
+                    bound = bounds[i] if bounds is not None else None
+                    resp = Response(
+                        rid=req.rid,
+                        kind=req.kind,
+                        stage1=stage1_answers[i],
+                        refined=(
+                            refined_answers[i] if refined_answers else None
+                        ),
+                        eps_granted=eps_used,
+                        compression_ratio=grant.compression_ratio,
+                        deadline_s=req.deadline_s,
+                        queue_wait_s=t_start - req.arrival_t,
+                        stage1_latency_s=stage1_latency,
+                        total_latency_s=total_latency,
+                        deadline_met=stage1_latency <= req.deadline_s,
+                        escalated=grant.escalate,
+                        reexecuted=req.reexecution,
+                        cache_hit=cache_hit,
+                        batch_size=batch.n,
+                        accuracy_proxy=(
+                            float(proxies[i]) if proxies is not None else None
+                        ),
+                        partial_shards=partial_shards,
+                        error_bound=bound,
+                        accuracy_met=(
+                            bound.met(req.max_error)
+                            if bound is not None and req.max_error is not None
+                            else None
+                        ),
+                        refine_skipped=refine_skipped,
+                    )
+                    responses.append(resp)
+                    self.metrics.record(resp)
+                    if grant.escalate and not req.reexecution:
+                        self._requeue_for_reexecution(req)
+                if self.slo is not None:
+                    # Evaluate inside the batch span so alert transitions
+                    # land as slo.alert events on this batch's tree.
+                    self.slo.evaluate()
+                return responses
 
     def _requeue_for_reexecution(self, req: Request) -> None:
         self.batcher.submit(

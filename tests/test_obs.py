@@ -3,6 +3,7 @@ the ServeMetrics reimplementation (bounded memory, API-compatible summary),
 and the serving-path span tree end to end."""
 import json
 import math
+import re
 from pathlib import Path
 
 import jax
@@ -10,8 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.apps import cf as cf_lib
+from repro.apps import knn as knn_lib
 from repro.apps.knn import KNNServable
 from repro.core import engine as engine_lib
+from repro.core import lsh as lsh_lib
 from repro.core.budget import BudgetPolicy, CostModel
 from repro.kernels import ops as kernel_ops
 from repro.obs.metrics import (
@@ -408,6 +412,40 @@ def test_engine_untraced_path_records_nothing():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x) * 2)
 
 
+class _Counting:
+    """Stand-in for a JAX entry point that counts its calls."""
+
+    def __init__(self, real):
+        self.real, self.calls = real, []
+
+    def __call__(self, *args, **kwargs):
+        self.calls.append(args[0] if args else None)
+        return self.real(*args, **kwargs)
+
+
+@pytest.mark.parametrize("mode", ["all_gather", "psum", "identity"])
+def test_engine_never_blocks_under_live_tracer(mode, monkeypatch):
+    """Spans time host work; the device trace says when the work ran, so
+    a live tracer adds no sync to the engine."""
+    block = _Counting(jax.block_until_ready)
+    monkeypatch.setattr(jax, "block_until_ready", block)
+    eng = engine_lib.MapReduce(mesh=None)
+    x = jnp.ones((8, 4))
+    tr = Tracer()
+    with use_tracer(tr):
+        out = eng.run(
+            lambda a: a * 2,
+            engine_lib.CombineSpec(mode=mode, reduce_fn=lambda o: o + 1),
+            x,
+        )
+    assert block.calls == []
+    (root,) = tr.traces()
+    want = ["map.shard"] + ([] if mode == "identity" else
+                            ["map.meter", "reduce"])
+    assert [c.name for c in root.children] == want
+    assert np.asarray(out).shape[-1] == 4
+
+
 # ---------------------------------------------------------------------------
 # kernel probe
 # ---------------------------------------------------------------------------
@@ -509,13 +547,23 @@ def test_supervisor_emits_shard_lifecycle_events(tmp_path):
 N_KNN, D_KNN, N_CLASSES = 256, 8, 5
 
 
-@pytest.fixture(scope="module")
-def knn_servable():
+def _make_knn_servable():
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (N_KNN, D_KNN))
     y = jax.random.randint(jax.random.fold_in(key, 1), (N_KNN,), 0, N_CLASSES)
     return KNNServable(x, y, n_classes=N_CLASSES, k=3,
                        lsh_key=jax.random.PRNGKey(7))
+
+
+@pytest.fixture(scope="module")
+def knn_servable():
+    return _make_knn_servable()
+
+
+@pytest.fixture
+def fresh_knn_servable():
+    """A servable of its own: its aggregate store starts empty."""
+    return _make_knn_servable()
 
 
 def _traced_server(knn_servable):
@@ -606,6 +654,132 @@ def test_untraced_server_stays_lean(knn_servable):
     (resp,) = [r for r in server.drain() if not r.reexecuted]
     assert resp.stage1 is not None
     assert NULL_TRACER.traces() == []
+
+
+def test_serve_batch_children_are_pinned(fresh_knn_servable):
+    """The per-batch tree's root and its direct children, by name: readers
+    of the tree (per-layer latency metrics) find each stage there."""
+    sv = fresh_knn_servable
+    server = _traced_server(sv)
+    server.submit("knn", (sv.train_x[0],), deadline_s=10.0)
+    server.submit("knn", (sv.train_x[1],), deadline_s=10.0)
+    server.step()
+    (root,) = server.tracer.traces()
+    assert root.name == "serve.batch" and root.parent_id is None
+    assert [c.name for c in root.children] == [
+        "batcher.wait", "batcher.wait", "deadline.grant", "cache.lookup",
+        "stage1", "stage2.refine", "serve.respond",
+    ]
+    for stage in ("stage1", "stage2.refine"):
+        (sp,) = root.find(stage)
+        assert [c.name for c in sp.children] == ["mapreduce"]
+
+
+def test_only_live_spans_open_profiler_annotations(fresh_knn_servable,
+                                                   monkeypatch):
+    """NULL_TRACER never enters ``jax.profiler.TraceAnnotation``; a live
+    tracer opens ``host.<name>`` for each ``span`` and none for
+    ``add_span``/``event`` (already over when recorded)."""
+    sv = fresh_knn_servable
+    ann = _Counting(jax.profiler.TraceAnnotation)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann)
+    untraced = Server(
+        [sv],
+        controller=DeadlineController(
+            BudgetPolicy(compression_ratio=20.0, eps_max=0.32), ema=0.0
+        ),
+        batcher=ContinuousBatcher(max_batch=4, pad_sizes=(4,)),
+    )
+    untraced.submit("knn", (sv.train_x[0],), deadline_s=10.0)
+    untraced.drain()
+    with NULL_TRACER.span("x"):
+        NULL_TRACER.event("y")
+    assert ann.calls == []
+
+    tr = Tracer()
+    with tr.span("outer"):
+        tr.add_span("waited", 0.0, 1.0)
+        tr.event("marker")
+        with tr.span("inner"):
+            pass
+    assert ann.calls == ["host.outer", "host.inner"]
+
+
+def test_spans_nest_on_the_profiler_timeline(fresh_knn_servable, tmp_path):
+    """A profile around one traced ``Server.step`` holds the program's spans
+    as ``host.*`` events, nested by time as the span tree nests."""
+    from bench import devtrace
+
+    sv = fresh_knn_servable
+    server = _traced_server(sv)
+    server.submit("knn", (sv.train_x[0],), deadline_s=10.0)
+    server.drain()                      # compile outside the profile
+    server.submit("knn", (sv.train_x[1],), deadline_s=10.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        server.step()
+    finally:
+        jax.profiler.stop_trace()
+    events = devtrace.read_profile(str(tmp_path))
+    by_name: dict[str, list] = {}
+    for e in events:
+        by_name.setdefault(e.name, []).append(e)
+
+    def inside(inner, outer):
+        return (outer.start_ns <= inner.start_ns
+                and inner.end_ns <= outer.end_ns)
+
+    (batch,) = by_name["host.serve.batch"]
+    (stage1,) = by_name["host.stage1"]
+    (respond,) = by_name["host.serve.respond"]
+    assert inside(stage1, batch) and inside(respond, batch)
+    assert respond.start_ns >= stage1.end_ns
+    (mr,) = [m for m in by_name["host.mapreduce"] if inside(m, stage1)]
+    for name in ("host.map.shard", "host.map.meter", "host.reduce"):
+        assert any(inside(e, mr) for e in by_name[name]), name
+    (refine,) = by_name["host.stage2.refine"]
+    assert inside(refine, batch) and refine.start_ns >= stage1.end_ns
+    assert respond.start_ns >= refine.end_ns
+
+
+def _small_knn_map_args():
+    key = jax.random.PRNGKey(0)
+    x = jax.random.normal(key, (N_KNN, D_KNN))
+    y = jax.random.randint(jax.random.fold_in(key, 1), (N_KNN,), 0,
+                           N_CLASSES)
+    cfg = lsh_lib.LSHConfig(n_hashes=4, bucket_width=4.0, n_buckets=32)
+    params = lsh_lib.init_lsh(jax.random.PRNGKey(7), D_KNN, cfg)
+    agg = knn_lib.build_knn_aggregates(x, y, params, N_CLASSES)
+    return knn_lib.accurateml_map, (x, y, agg, x[:4]), {"k": 3}
+
+
+def _small_cf_map_args():
+    key = jax.random.PRNGKey(2)
+    m = (jax.random.uniform(key, (96, 24)) < 0.3).astype(jnp.float32)
+    r = (jax.random.uniform(jax.random.fold_in(key, 1), (96, 24)) * 4 + 1) * m
+    cfg = lsh_lib.LSHConfig(n_hashes=4, bucket_width=4.0, n_buckets=16)
+    params = lsh_lib.init_lsh(jax.random.PRNGKey(8), 24, cfg)
+    agg = cf_lib.build_cf_aggregates(r, m, params)
+    return cf_lib.accurateml_map, (r, m, agg, r[:4], m[:4]), {}
+
+
+STAGE2_SCOPES = ("stage2.centroids", "stage2.select", "stage2.rows",
+                 "stage2.merge")
+
+
+@pytest.mark.parametrize("app", ["knn", "cf"])
+@pytest.mark.parametrize("budget", [0, 24])
+def test_map_device_phases_carry_named_scopes(app, budget):
+    """The lowered map names its device phases: ``stage1`` at budget 0, the
+    four ``stage2.*`` phases (and no ``stage1``) when it refines."""
+    fn, args, kw = {"knn": _small_knn_map_args,
+                    "cf": _small_cf_map_args}[app]()
+    text = fn.lower(
+        *args, refine_budget=budget, with_bound=True, **kw
+    ).as_text(debug_info=True)
+    scopes = set(re.findall(r'"jit\(accurateml_map\)/([^/"]+)/', text))
+    want = {"stage1"} if budget == 0 else set(STAGE2_SCOPES)
+    assert scopes == want
 
 
 def test_knn_accuracy_proxy_is_zero_for_identical_outputs(knn_servable):
