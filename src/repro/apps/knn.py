@@ -26,6 +26,7 @@ from repro.core import engine as engine_lib
 from repro.core import lsh as lsh_lib
 from repro.core import refine as refine_lib
 from repro.kernels import ops as kernel_ops
+from repro.kernels.refine_distances import row_table
 from repro.kernels.topk_stream import BIG  # shared sentinel: one definition
 from repro.serve import servable as serve_servable
 from repro.serve.request import ErrorBound
@@ -331,6 +332,10 @@ def accurateml_map(
     (``label * (K+1) + bucket``; refined originals use the exact-candidate
     sentinel bucket K, which has zero spread/dispersion), so provenance
     survives the streaming merges without a second kernel pass.
+
+    Only stage 2 reads ``train_x``: the [N, D] table, or its row table
+    (``kernels.refine_distances.row_table``), which the row walk reads
+    without a relayout.
     """
     agg = knn_agg.agg
     n_k = agg.means.shape[0]                                  # K (static)
@@ -391,7 +396,7 @@ def accurateml_map(
         covered = covered & (agg.counts[None, :] > 0)
 
     # Gather-free exact distances: each selected original is read straight
-    # from HBM by the scalar-prefetch kernel ([Q,B,D] never materializes).
+    # from HBM by the row-walk kernel ([Q,B,D] never materializes).
     with jax.named_scope("stage2.rows"):
         d_ref = kernel_ops.refine_distances(test_x, train_x, idx, valid)
         ref_y = train_y[idx]                                 # [Q, B] ints
@@ -538,6 +543,9 @@ class KNNServable(serve_servable.LSHServableBase):
             pyramid_spec=pyramid_spec,
         )
         self.train_x = train_x
+        # The table as stage 2's row walk reads it, laid out once here
+        # rather than on every refined batch.
+        self.train_rows = row_table(train_x)
         self.train_y = train_y
         self.n_classes = n_classes
         self.k = k
@@ -592,7 +600,7 @@ class KNNServable(serve_servable.LSHServableBase):
             mode="all_gather", reduce_fn=reduce_fn,
         )
         return self.engine.run(
-            map_fn, combine, self.train_x, self.train_y,
+            map_fn, combine, self.train_rows, self.train_y,
             replicated_args=(prepared, test_x),
         )
 

@@ -14,6 +14,6 @@ Layout per kernel:
 The fused two-stage hot path (`distance_topk`, `topk_stream`,
 `refine_distances`, `cf_refine`) replaces materialize-then-reduce with
 stream-and-carry: a per-query running k-best lives in VMEM scratch across
-grid steps and refinement rows are scalar-prefetch DMA'd from HBM, so the
+grid steps and refinement rows are DMA'd from HBM one by one, so the
 [Q,N] distance matrix and [Q,B,D]/[Q,B,I] gathered tensors never exist.
 """

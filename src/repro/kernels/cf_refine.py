@@ -15,9 +15,9 @@ never touch HBM.
 
 Every [rows, I] operand is viewed as [rows, 1, I] with block (None, 1, I)
 and the per-pair weights form a [Q, B, 1, 1] output, the layout Mosaic
-accepts for one-row blocks (see ``refine_distances``).  Large selections
-are walked in SMEM-sized chunks; the running sums enter each chunk as its
-accumulators' initial value, so the sums add in one pass's order.
+accepts for one-row blocks.  Large selections are walked in SMEM-sized
+chunks; the running sums enter each chunk as its accumulators' initial
+value, so the sums add in one pass's order.
 
 ``use`` gates candidates exactly like the einsum path: a non-used slot
 contributes zero weight and zero sums (never NaN — the denominator is
@@ -33,8 +33,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
-from repro.kernels.refine_distances import chunk_selection, split_chunks
 from repro.kernels.topk_stream import pad_to_multiple
+
+# (query, slot) pairs per chunk: two int32 scalar-prefetch operands of this
+# many entries take 256 KiB of the 1 MiB SMEM.
+SMEM_PAIRS = 32_768
+
+
+def chunk_selection(nq: int, nb: int) -> tuple[int, int]:
+    """(slots per chunk, number of chunks) for a [nq, nb] selection."""
+    chunk = max(1, min(nb, SMEM_PAIRS // max(nq, 1)))
+    return chunk, -(-nb // chunk)
+
+
+def split_chunks(a: jax.Array, chunk: int, n_chunks: int) -> jax.Array:
+    """[Q, B] -> [n_chunks, Q, chunk], zero-padding the slot axis."""
+    a = pad_to_multiple(a, chunk, 1)
+    return a.reshape(a.shape[0], n_chunks, chunk).transpose(1, 0, 2)
 
 
 def _kernel(idx_ref, use_ref, ac_ref, am_ref, uc_ref, um_ref,

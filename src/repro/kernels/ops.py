@@ -254,7 +254,8 @@ def refine_distances(
     idx: jax.Array, valid: jax.Array,
     *, force: str | None = None,
 ) -> jax.Array:
-    """Gather-free stage-2 exact distances: [Q,B] with BIG-masked padding."""
+    """Gather-free stage-2 exact distances: [Q,B] with BIG-masked padding.
+    ``train_x`` is the [N,D] table or its `row_table`."""
     force = _resolve(force)
     if force == "ref":
         return ref.refine_distances(queries, train_x, idx, valid)

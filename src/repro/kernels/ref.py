@@ -98,8 +98,12 @@ def refine_distances(
     """Per-query exact distances to selected originals, BIG-masked padding.
 
     [Q,D],[N,D],[Q,B],[Q,B] -> [Q,B].  The oracle gathers [Q,B,D]; the
-    Pallas kernel reads each selected row straight from HBM instead.
+    Pallas kernel reads each selected row straight from HBM instead.  The
+    table may also be its row table ([N, 1, Dp], features zero-padded; see
+    ``refine_distances.row_table``), of which the first D features count.
     """
+    if train_x.ndim == 3:
+        train_x = train_x[:, 0, :queries.shape[1]]
     qf = queries.astype(jnp.float32)
     ref_x = train_x.astype(jnp.float32)[idx]                # [Q, B, D]
     q2 = jnp.sum(qf * qf, axis=-1)                          # [Q]

@@ -272,28 +272,70 @@ def test_candidate_topk_seeded_property(q, m, k):
     )
 
 
+# Shapes of the row-walk cases: a drawn shape, then fixed ones around the
+# row block R (``rows_max`` shrinks ``ROWS_MAX``; R is at least 128).
+REFINE_CASES = {
+    "drawn": None,
+    "below-one-block-q1": (1, 60, 217, 40, None),
+    "one-block-q4": (4, 300, 217, 128, None),
+    "ragged-blocks-q4": (4, 700, 217, 300, 128),
+    "ragged-blocks-q1": (1, 700, 217, 300, 128),
+}
+
+
+@pytest.mark.parametrize("case", list(REFINE_CASES))
 @settings(max_examples=10, deadline=None)
-@given(
-    q=st.integers(min_value=1, max_value=30),
-    n=st.integers(min_value=1, max_value=120),
-    d=st.integers(min_value=1, max_value=200),
-    b=st.integers(min_value=1, max_value=40),
-)
-def test_refine_distances_property(q, n, d, b):
-    """Scalar-prefetch gather-free distances == gathered-einsum oracle,
-    including all-padding selections (valid everywhere False)."""
-    key = jax.random.PRNGKey(q + n * 13 + d * 101 + b)
+@given(data=st.data())
+def test_refine_distances_property(case, data):
+    """Row-walk distances == gathered-einsum oracle, from the plain table
+    and from its row table, with duplicate indices allowed, including
+    all-padding selections (valid everywhere False)."""
+    from repro.kernels import refine_distances as rd
+
+    if REFINE_CASES[case] is None:
+        q = data.draw(st.integers(min_value=1, max_value=30))
+        n = data.draw(st.integers(min_value=1, max_value=120))
+        d = data.draw(st.integers(min_value=1, max_value=200))
+        b = data.draw(st.integers(min_value=1, max_value=40))
+        rows_max = None
+    else:
+        q, n, d, b, rows_max = REFINE_CASES[case]
+    with pytest.MonkeyPatch.context() as mp:
+        if rows_max is not None:
+            mp.setattr(rd, "ROWS_MAX", rows_max)
+        # One jit per case: its row block is fixed while it traces.
+        walk = _REFINE_WALKS.setdefault(case, jax.jit(
+            rd.refine_distances_pallas.__wrapped__,
+            static_argnames="interpret",
+        ))
+        _check_refine_distances(walk, data, q, n, d, b)
+
+
+_REFINE_WALKS = {}
+
+
+def _check_refine_distances(walk, data, q, n, d, b):
+    from repro.kernels import refine_distances as rd
+
+    # Few distinct rows force duplicates within and across blocks.
+    distinct = data.draw(st.sampled_from([n, min(n, 3)]))
+    key = jax.random.PRNGKey(data.draw(st.integers(0, 2**16)))
     qs = jax.random.normal(key, (q, d))
     xs = jax.random.normal(jax.random.fold_in(key, 1), (n, d))
-    idx = jax.random.randint(jax.random.fold_in(key, 2), (q, b), 0, n)
+    idx = jax.random.randint(jax.random.fold_in(key, 2), (q, b), 0, distinct)
     valid = jax.random.uniform(jax.random.fold_in(key, 3), (q, b)) < 0.7
-    got = refine_distances_pallas(qs, xs, idx, valid, interpret=True)
     want = ref.refine_distances(qs, xs, idx, valid)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
+    for table in (xs, rd.row_table(xs)):
+        got = walk(qs, table, idx, valid, interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(
+        np.asarray(ref.refine_distances(qs, rd.row_table(xs), idx, valid)),
+        np.asarray(want),
+    )
     # all-padding bucket: every slot masked -> pure BIG row
     none = jnp.zeros_like(valid)
-    got0 = refine_distances_pallas(qs, xs, idx, none, interpret=True)
+    got0 = walk(qs, xs, idx, none, interpret=True)
     assert (np.asarray(got0) >= float(BIG) / 2).all()
 
 
@@ -316,27 +358,33 @@ def test_cf_refine_kernel(qn, un, ni, b):
 
 
 def test_refine_kernels_chunked_walk_equals_one_pass(monkeypatch):
-    """A selection too large for SMEM is walked in chunks; the chunked walk
-    returns exactly what one pass does (CF sums add in the same order)."""
+    """A kNN selection walked in several row blocks returns exactly what one
+    block does; a CF selection too large for SMEM is walked in chunks and
+    returns exactly what one pass does (its sums add in the same order)."""
+    from repro.kernels import cf_refine as cr
     from repro.kernels import refine_distances as rd
 
     key = jax.random.PRNGKey(21)
     xs = jax.random.normal(key, (50, 10))
     qs = jax.random.normal(jax.random.fold_in(key, 1), (3, 10))
-    idx = jax.random.randint(jax.random.fold_in(key, 2), (3, 17), 0, 50)
-    valid = jax.random.uniform(jax.random.fold_in(key, 3), (3, 17)) < 0.7
+    idx = jax.random.randint(jax.random.fold_in(key, 2), (3, 300), 0, 50)
+    valid = jax.random.uniform(jax.random.fold_in(key, 3), (3, 300)) < 0.7
     r = jax.random.randint(key, (20, 15), 0, 6).astype(jnp.float32)
     m = (jax.random.uniform(jax.random.fold_in(key, 4), (20, 15)) < 0.4
          ).astype(jnp.float32)
-    cf_args = ((r * m)[:3], m[:3], (r * m)[3:], m[3:], idx % 17, valid)
+    cf_args = ((r * m)[:3], m[:3], (r * m)[3:], m[3:], idx[:, :17] % 17,
+               valid[:, :17])
 
-    # Unjitted wrappers: each call traces afresh with the current chunk size.
+    # Unjitted wrappers: each call traces afresh with the current sizes.
     dist = refine_distances_pallas.__wrapped__
     cf = cf_refine_pallas.__wrapped__
+    assert rd.rows_per_step(300, 128) == 384
     one_d = dist(qs, xs, idx, valid, interpret=True)
     one_cf = cf(*cf_args, shrink=8.0, interpret=True)
-    monkeypatch.setattr(rd, "SMEM_PAIRS", 6)
-    assert rd.chunk_selection(3, 17) == (2, 9)
+    monkeypatch.setattr(rd, "ROWS_MAX", 128)
+    monkeypatch.setattr(cr, "SMEM_PAIRS", 6)
+    assert rd.rows_per_step(300, 128) == 128
+    assert cr.chunk_selection(3, 17) == (2, 9)
     np.testing.assert_array_equal(
         np.asarray(dist(qs, xs, idx, valid, interpret=True)),
         np.asarray(one_d),
